@@ -19,9 +19,10 @@
 from __future__ import annotations
 
 import argparse
+import traceback
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma list: table1,eq3,resources,kernels,roofline,"
@@ -41,6 +42,8 @@ def main() -> None:
     args = ap.parse_args()
     want = set(args.only.split(",")) if args.only else None
 
+    from repro.launch import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (engine_bench, kernel_bench, mrf_serve_bench,
                             roofline_report, serve_autotune, table1_metrics,
                             table_eq3_timing, table_resources)
@@ -58,15 +61,22 @@ def main() -> None:
         ("table1", table1_metrics.run, {"steps": args.steps}),
     ]
     print("name,us_per_call,derived")
+    failed = []
     for key, fn, kw in suites:
         if want and key not in want:
             continue
         try:
             for name, us, derived in fn(**kw):
                 print(f'{name},{us:.2f},"{derived}"', flush=True)
-        except Exception as e:  # keep the harness running
+        except Exception as e:  # run the other suites, then fail the run
+            traceback.print_exc()
             print(f'{key}/ERROR,0,"{type(e).__name__}: {e}"', flush=True)
+            failed.append(key)
+    if failed:
+        print(f"FAILED suites: {','.join(failed)}", flush=True)
+        return 1
+    return 0
 
 
 if __name__ == '__main__':
-    main()
+    raise SystemExit(main())

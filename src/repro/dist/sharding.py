@@ -28,9 +28,9 @@ The pieces:
 - :func:`param_shardings` — axes pytree -> ``NamedSharding`` pytree for
   ``jit`` in/out shardings, checkpoint restore, and elastic resharding.
 
-``make_compat_mesh`` papers over the ``jax.make_mesh`` signature change
-(``axis_types=AxisType.Auto`` is mandatory for auto-sharding on newer jax,
-nonexistent on 0.4.x); all mesh construction in this repo routes through it.
+``make_compat_mesh`` builds every mesh in this repo with
+``axis_types=AxisType.Auto`` on each axis, the GSPMD auto-sharding this
+layer relies on.
 """
 
 from __future__ import annotations
@@ -39,7 +39,7 @@ import dataclasses
 from typing import Any, Mapping, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 # A logical axis maps to: one mesh axis, several (sharded over their
 # product), or None (replicated).
@@ -184,23 +184,14 @@ def param_shardings(axes_tree, rules: AxisRules):
 
 
 # --------------------------------------------------------------------------
-# mesh construction compat
+# mesh construction
 # --------------------------------------------------------------------------
 
 def make_compat_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
                      *, devices=None) -> Mesh:
-    """``jax.make_mesh`` across jax versions.
-
-    Newer jax (>= 0.5, explicit-sharding era) requires
-    ``axis_types=(AxisType.Auto, ...)`` for the GSPMD auto-sharding this
-    layer relies on; jax 0.4.x has neither ``AxisType`` nor the kwarg and
-    is Auto-only.  Every mesh in the repo (production, dry-run, tests)
-    comes from here so the divergence lives in one place.
-    """
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(axis_shapes, axis_names, devices=devices)
+    """``jax.make_mesh`` with every axis ``AxisType.Auto`` — the GSPMD
+    auto-sharding this layer relies on.  Every mesh in the repo
+    (production, dry-run, tests) comes from here."""
     return jax.make_mesh(axis_shapes, axis_names,
                          axis_types=(AxisType.Auto,) * len(axis_names),
                          devices=devices)

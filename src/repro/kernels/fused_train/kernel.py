@@ -30,7 +30,8 @@ step.
 
 Two update modes:
 * ``tile_batch = 1``  -> per-sample streaming SGD, the *faithful* FPGA
-  algorithm (one update per training signal);
+  algorithm (one update per training signal) — interpreter only: the
+  compiled kernel needs tiles of a multiple of ``TILE_ALIGN`` rows;
 * ``tile_batch = T``  -> minibatch update per tile, the MXU-native
   reformulation (beyond-paper optimization; see EXPERIMENTS.md §Perf).
 
@@ -52,6 +53,17 @@ import jax.experimental.pallas.tpu as pltpu
 from repro.kernels.common import resolve_interpret
 
 PAD = 128  # MXU lane width; every layer is padded to this many nodes.
+
+# Mosaic tiles a VMEM block's last two dims by (8, 128), so a batch tile
+# must be a multiple of 8 rows to compile (the interpreter takes any).
+TILE_ALIGN = 8
+
+# The per-tile losses (and Adam's per-tile bias corrections) are whole 1-D
+# SMEM arrays indexed by ``program_id``: one f32 per tile.  SMEM holds
+# 1 MiB, so a launch is capped at this many tiles (Adam's three such
+# arrays then take 384 KiB).
+MAX_LAUNCH_TILES = 1 << 15
+SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def train_tile(x, y, w_s, b_s, h_s, update, *, n_layers: int, out_dim: int,
@@ -101,7 +113,11 @@ def train_tile(x, y, w_s, b_s, h_s, update, *, n_layers: int, out_dim: int,
             dh = jnp.dot(dz, w_l.T, preferred_element_type=jnp.float32)
             relu_mask = (h_prev > 0.0).astype(jnp.float32)
         dw = jnp.dot(h_prev.T, dz, preferred_element_type=jnp.float32)
-        db = jnp.sum(dz, axis=0)
+        # the bias gradient's column sum runs on the MXU like dw: a reduce
+        # compiles to a different summation order depending on the program
+        # around it, which broke chunked == stepwise bit-parity
+        db = jnp.dot(jnp.ones((TILE_ALIGN, tb), jnp.float32), dz,
+                     preferred_element_type=jnp.float32)[0]
         update(l, dw, db)
         if l > 0:
             dz = dh * relu_mask
@@ -129,7 +145,7 @@ def _kernel(x_ref, y_ref, w_in_ref, b_in_ref,            # inputs
         w_s[...] = w_in_ref[...]
         b_s[...] = b_in_ref[...]
 
-    loss_ref[0, 0] = train_tile(
+    loss_ref[i] = train_tile(
         x_ref[...], y_ref[...], w_s, b_s, h_s, _sgd_update(w_s, b_s, lr),
         n_layers=n_layers, out_dim=out_dim, qat=qat)
 
@@ -152,9 +168,9 @@ def fused_train_call(x_pad, y_pad, w_pad, b_pad, *, n_layers: int, out_dim: int,
     Returns (w_new, b_new, per_tile_losses (B//tile_batch,)).
     ``interpret=None`` auto-detects: compiled on TPU, interpreter elsewhere.
 
-    This is the single-step (K=1) kernel; multi-step launches with weights
-    resident across steps — and the in-kernel Adam variant — live in
-    ``multistep.py`` (``fused_train_multistep_call``).
+    Over K steps' batches staged back to back this is the multi-step launch
+    (``multistep.fused_train_multistep_call``): the grid runs the tiles in
+    order, so weights stay resident in VMEM across every step.
     """
     interpret = resolve_interpret(interpret)
     batch, _ = x_pad.shape
@@ -174,12 +190,12 @@ def fused_train_call(x_pad, y_pad, w_pad, b_pad, *, n_layers: int, out_dim: int,
         out_specs=[
             pl.BlockSpec((n_layers, PAD, PAD), lambda i: (0, 0, 0)),
             pl.BlockSpec((n_layers, PAD), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),  # jaxlint: disable=PALLASTILE -- one scalar loss per grid step; pads one tile, negligible next to the weights
+            SMEM_SPEC,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_layers, PAD, PAD), jnp.float32),
             jax.ShapeDtypeStruct((n_layers, PAD), jnp.float32),
-            jax.ShapeDtypeStruct((n_tiles, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n_tiles,), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((n_layers, PAD, PAD), jnp.float32),       # weights
@@ -188,4 +204,4 @@ def fused_train_call(x_pad, y_pad, w_pad, b_pad, *, n_layers: int, out_dim: int,
         ],
         interpret=interpret,
     )(x_pad, y_pad, w_pad, b_pad)
-    return w_new, b_new, losses[:, 0]
+    return w_new, b_new, losses

@@ -25,9 +25,10 @@ Two optimizer rules, selected statically:
 * **Adam** (``fused_train_adam_call``) — the paper's *software* baseline,
   now in-kernel: first/second moment stacks ride as extra input/output refs
   plus VMEM scratch (same residency as the weights), and the bias
-  correction is driven by the traced global Adam step ``step0`` (an SMEM
-  scalar), with ``t = step0 + tile_index + 1`` — each batch tile is one
-  Adam update, the sequential-update regime the SGD kernel already uses.
+  corrections come from the traced global Adam step ``step0``, with
+  ``t = step0 + tile_index + 1`` — each batch tile is one Adam update, the
+  sequential-update regime the SGD kernel already uses.  The wrapper
+  computes them per tile and the kernel reads them from SMEM.
   The update formula mirrors ``optim.optimizers.adam`` op for op, so given
   the same gradients it produces the same bits as the engine's software
   Adam on the padded math (zero-padded lanes have g = m = v = 0 and stay
@@ -44,7 +45,8 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from repro.kernels.common import resolve_interpret
-from repro.kernels.fused_train.kernel import PAD, _kernel, train_tile
+from repro.kernels.fused_train.kernel import (PAD, SMEM_SPEC,
+                                              fused_train_call, train_tile)
 
 # Adam defaults — must match optim.optimizers.adam for the engine's
 # fused path to be interchangeable with the software optimizer.
@@ -53,62 +55,15 @@ _ADAM_B2 = 0.999
 _ADAM_EPS = 1e-8
 
 
-@functools.partial(jax.jit, static_argnames=("n_layers", "out_dim", "lr",
-                                             "tile_batch", "qat", "interpret"))
-def fused_train_multistep_call(x_pad, y_pad, w_pad, b_pad, *, n_layers: int,
-                               out_dim: int, lr: float, tile_batch: int,
-                               qat: bool = False,
-                               interpret: bool | None = None):
-    """K steps of in-kernel SGD in one launch, weights VMEM-resident
-    throughout.
-
-    x_pad/y_pad: ``(K*B, PAD)`` fp32 — K steps' batches pre-staged back to
-    back (step k = rows ``[k*B, (k+1)*B)``); ``K*B`` must be a multiple of
-    ``tile_batch``, and ``tile_batch`` must divide the per-step batch ``B``
-    so no tile straddles a step boundary (``ops.effective_tile`` guarantees
-    this).  Returns ``(w_new, b_new, per_tile_losses (K*B//tile_batch,))``
-    — the caller regroups tiles into the ``(K,)`` per-step loss trace.
-
-    The SGD rule needs no extra state, so this is literally the single-step
-    kernel body run over the flattened ``(K * n_tiles,)`` grid: the
-    single-step call is the K=1 special case.
-    """
-    interpret = resolve_interpret(interpret)
-    total, _ = x_pad.shape
-    assert total % tile_batch == 0, (total, tile_batch)
-    n_tiles = total // tile_batch
-    kern = functools.partial(_kernel, n_layers=n_layers, out_dim=out_dim,
-                             lr=lr, n_tiles=n_tiles, qat=qat)
-    w_new, b_new, losses = pl.pallas_call(
-        kern,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((tile_batch, PAD), lambda i: (i, 0)),   # x tile
-            pl.BlockSpec((tile_batch, PAD), lambda i: (i, 0)),   # y tile
-            pl.BlockSpec((n_layers, PAD, PAD), lambda i: (0, 0, 0)),
-            pl.BlockSpec((n_layers, PAD), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((n_layers, PAD, PAD), lambda i: (0, 0, 0)),
-            pl.BlockSpec((n_layers, PAD), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),  # jaxlint: disable=PALLASTILE -- one scalar loss per grid step; pads one tile, negligible next to the weights
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_layers, PAD, PAD), jnp.float32),
-            jax.ShapeDtypeStruct((n_layers, PAD), jnp.float32),
-            jax.ShapeDtypeStruct((n_tiles, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((n_layers, PAD, PAD), jnp.float32),       # weights
-            pltpu.VMEM((n_layers, PAD), jnp.float32),            # biases
-            pltpu.VMEM((max(n_layers - 1, 1), tile_batch, PAD), jnp.float32),
-        ],
-        interpret=interpret,
-    )(x_pad, y_pad, w_pad, b_pad)
-    return w_new, b_new, losses[:, 0]
+# K steps of in-kernel SGD in one launch is the single-step kernel over the
+# K steps' batches staged back to back: x_pad/y_pad are ``(K*B, PAD)``, the
+# tile must divide the per-step batch B so no tile straddles a step
+# (``ops.effective_tile`` picks it), and the ``(K*B//tile_batch,)`` per-tile
+# losses regroup into the ``(K, n_tiles)`` per-step trace.
+fused_train_multistep_call = fused_train_call
 
 
-def _adam_kernel(step0_ref,                               # SMEM scalar
+def _adam_kernel(c1_ref, c2_ref,                          # SMEM, per tile
                  x_ref, y_ref, w_in_ref, b_in_ref,        # inputs
                  mw_in_ref, mb_in_ref, vw_in_ref, vb_in_ref,
                  w_out_ref, b_out_ref,                    # outputs
@@ -129,12 +84,8 @@ def _adam_kernel(step0_ref,                               # SMEM scalar
         vw_s[...] = vw_in_ref[...]
         vb_s[...] = vb_in_ref[...]
 
-    # bias correction from the traced global Adam step: each tile is one
-    # update, so update t of this launch is step0 + i + 1 — exactly the
-    # counter optim.optimizers.adam would have reached.
-    t = (step0_ref[0, 0] + i + 1).astype(jnp.float32)
-    c1 = 1.0 - jnp.power(b1, t)
-    c2 = 1.0 - jnp.power(b2, t)
+    c1 = c1_ref[i]
+    c2 = c2_ref[i]
 
     def update(l, dw, db):
         # mirrors optim.optimizers.adam.upd op for op — including the
@@ -151,7 +102,7 @@ def _adam_kernel(step0_ref,                               # SMEM scalar
             m_s[l] = m
             v_s[l] = v
 
-    loss_ref[0, 0] = train_tile(
+    loss_ref[i] = train_tile(
         x_ref[...], y_ref[...], w_s, b_s, h_s, update,
         n_layers=n_layers, out_dim=out_dim, qat=qat)
 
@@ -178,7 +129,7 @@ def fused_train_adam_call(step0, x_pad, y_pad, w_pad, b_pad, mw_pad, mb_pad,
     """K steps of in-kernel Adam in one launch: weights and both moment
     stacks VMEM-resident throughout.
 
-    ``step0``: ``(1, 1)`` int32 — the Adam step counter *before* this launch
+    ``step0``: int32 scalar — the Adam step counter *before* this launch
     (traced, so chunk dispatches never recompile as the run advances).
     ``mw/mb/vw/vb``: first/second-moment stacks, padded exactly like the
     weights.  Returns ``(w, b, mw, mb, vw, vb, per_tile_losses)``.
@@ -187,6 +138,13 @@ def fused_train_adam_call(step0, x_pad, y_pad, w_pad, b_pad, mw_pad, mb_pad,
     total, _ = x_pad.shape
     assert total % tile_batch == 0, (total, tile_batch)
     n_tiles = total // tile_batch
+    # bias corrections, one pair per tile: tile i is Adam update
+    # step0 + i + 1, the counter optim.optimizers.adam would have reached,
+    # and c1/c2 use its expression.  Computed here, in XLA, because Mosaic
+    # cannot lower a scalar powf.
+    t = (step0 + 1 + jnp.arange(n_tiles, dtype=jnp.int32)).astype(jnp.float32)
+    c1 = 1.0 - jnp.power(b1, t)
+    c2 = 1.0 - jnp.power(b2, t)
     kern = functools.partial(_adam_kernel, n_layers=n_layers, out_dim=out_dim,
                              lr=lr, b1=b1, b2=b2, eps=eps,
                              weight_decay=weight_decay, n_tiles=n_tiles,
@@ -197,7 +155,7 @@ def fused_train_adam_call(step0, x_pad, y_pad, w_pad, b_pad, mw_pad, mb_pad,
         kern,
         grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),               # step0 scalar
+            SMEM_SPEC, SMEM_SPEC,                                 # c1, c2
             pl.BlockSpec((tile_batch, PAD), lambda i: (i, 0)),   # x tile
             pl.BlockSpec((tile_batch, PAD), lambda i: (i, 0)),   # y tile
             stack3, stack2,                                       # w, b
@@ -208,7 +166,7 @@ def fused_train_adam_call(step0, x_pad, y_pad, w_pad, b_pad, mw_pad, mb_pad,
             stack3, stack2,                                       # w, b
             stack3, stack2,                                       # mu
             stack3, stack2,                                       # nu
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),  # jaxlint: disable=PALLASTILE -- one scalar loss per grid step; pads one tile, negligible next to the weights
+            SMEM_SPEC,                                            # losses
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_layers, PAD, PAD), jnp.float32),
@@ -217,7 +175,7 @@ def fused_train_adam_call(step0, x_pad, y_pad, w_pad, b_pad, mw_pad, mb_pad,
             jax.ShapeDtypeStruct((n_layers, PAD), jnp.float32),
             jax.ShapeDtypeStruct((n_layers, PAD, PAD), jnp.float32),
             jax.ShapeDtypeStruct((n_layers, PAD), jnp.float32),
-            jax.ShapeDtypeStruct((n_tiles, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n_tiles,), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((n_layers, PAD, PAD), jnp.float32),       # weights
@@ -229,6 +187,5 @@ def fused_train_adam_call(step0, x_pad, y_pad, w_pad, b_pad, mw_pad, mb_pad,
             pltpu.VMEM((max(n_layers - 1, 1), tile_batch, PAD), jnp.float32),
         ],
         interpret=interpret,
-    )(step0, x_pad, y_pad, w_pad, b_pad, mw_pad, mb_pad, vw_pad, vb_pad)
-    *stacks, losses = outs
-    return (*stacks, losses[:, 0])
+    )(c1, c2, x_pad, y_pad, w_pad, b_pad, mw_pad, mb_pad, vw_pad, vb_pad)
+    return tuple(outs)

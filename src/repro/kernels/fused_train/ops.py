@@ -12,7 +12,9 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels.fused_train.kernel import PAD, fused_train_call
+from repro.kernels.common import resolve_interpret
+from repro.kernels.fused_train.kernel import (MAX_LAUNCH_TILES, PAD, TILE_ALIGN,
+                                              fused_train_call)
 from repro.kernels.fused_train.multistep import (fused_train_adam_call,
                                                 fused_train_multistep_call)
 from repro.optim.optimizers import AdamState
@@ -61,13 +63,21 @@ def fused_train_step(params, x, y, *, lr: float, tile_batch: int = 128,
     return unpad_params(w_new, b_new, params), losses
 
 
-def effective_tile(batch: int, tile_batch: int) -> int:
+def effective_tile(batch: int, tile_batch: int, *,
+                   interpret: bool | None = None) -> int:
     """Largest tile <= tile_batch that divides ``batch`` (kernel grid
-    constraint); degrades toward per-sample streaming rather than crashing
-    on awkward batch sizes."""
-    t = min(tile_batch, batch)
-    while batch % t:
-        t -= 1
+    constraint).  In the interpreter any divisor does, down to per-sample
+    streaming; the compiled kernel needs a multiple of ``TILE_ALIGN``, and
+    a batch with no such divisor is refused with ``ValueError``."""
+    step = 1 if resolve_interpret(interpret) else TILE_ALIGN
+    t = min(tile_batch, batch) // step * step
+    while t and batch % t:
+        t -= step
+    if not t:
+        raise ValueError(
+            f"the compiled fused-pallas kernel needs a batch tile that is a "
+            f"multiple of {TILE_ALIGN} rows, at most tile_batch={tile_batch} "
+            f"and dividing the per-step batch {batch}; none exists")
     return t
 
 
@@ -98,8 +108,13 @@ def fused_train_multistep(params, opt_state, x, y, *, n_steps: int, lr: float,
         raise ValueError(f"staged stream of {total} rows is not divisible "
                          f"into n_steps={n_steps} equal batches")
     per_step = total // n_steps
-    tile = effective_tile(per_step, tile_batch)
+    tile = effective_tile(per_step, tile_batch, interpret=interpret)
     n_tiles = per_step // tile
+    if n_steps * n_tiles > MAX_LAUNCH_TILES:
+        raise ValueError(
+            f"one launch of {n_steps} steps x {n_tiles} tiles exceeds "
+            f"MAX_LAUNCH_TILES={MAX_LAUNCH_TILES} (per-tile values live in "
+            f"SMEM): use fewer chunk steps or larger tiles")
     assert d_in <= PAD, f"feature dim {d_in} > PAD={PAD}"
     x_pad = jnp.zeros((total, PAD), jnp.float32).at[:, :d_in].set(x)
     y_pad = jnp.zeros((total, PAD), jnp.float32).at[:, :out_dim].set(y)
@@ -119,7 +134,7 @@ def fused_train_multistep(params, opt_state, x, y, *, n_steps: int, lr: float,
                 " — build it with optim.optimizers.adam(lr).init(params)")
         mw_pad, mb_pad = pad_params(opt_state.mu)
         vw_pad, vb_pad = pad_params(opt_state.nu)
-        step0 = opt_state.step.astype(jnp.int32).reshape(1, 1)
+        step0 = opt_state.step.astype(jnp.int32)
         (w_new, b_new, mw_new, mb_new, vw_new, vb_new,
          tile_losses) = fused_train_adam_call(
             step0, x_pad, y_pad, w_pad, b_pad, mw_pad, mb_pad, vw_pad, vb_pad,

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke
+from repro.launch import enable_compile_cache
 from repro.models import registry
 from repro.models.encdec import enc_len_for
 from repro.serve.decode import make_prefill_step, make_serve_step
@@ -271,6 +272,13 @@ def run_mrf_serve(args, cfg) -> int:
         results = [t.result for t in tickets]
     else:
         results = engine.reconstruct(requests)
+    health = engine.health()
+    if health["degraded"] or health["n_kernel_failures"]:
+        # healthy serving must not lean on the circuit breaker: a kernel
+        # that fails here would otherwise serve from the lax fallback
+        print(f"FAIL: engine served degraded ({health['degraded_reason']}; "
+              f"{health['n_kernel_failures']} kernel failure(s))")
+        return 1
     wave = engine.last_wave
     pct = latency_percentiles(results)
     print(f"arch={cfg.name} backend={backend} mode={args.serve_mode} "
@@ -371,6 +379,7 @@ def main(argv=None):
     ap.add_argument("--phantom-n", type=int, default=32,
                     help="mrf: phantom slice side length")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     if cfg.family == "mrf":

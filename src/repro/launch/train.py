@@ -25,6 +25,7 @@ from repro.configs import get_config, get_smoke
 from repro.data.lm_text import TextPipeline
 from repro.dist.sharding import use_rules
 from repro.ft.runner import RunnerConfig, run
+from repro.launch import enable_compile_cache
 from repro.models import registry
 from repro.models.encdec import enc_len_for
 from repro.optim import adam
@@ -162,6 +163,7 @@ def main(argv=None):
                     default="none")
     ap.add_argument("--inject-fault-at", type=int, default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     if cfg.family == "mrf":

@@ -60,6 +60,7 @@ from repro.data.epg import default_sequence
 from repro.data.pipeline import MRFSampleStream, batch_at, make_batch_factory
 from repro.ft.checkpoint import latest_step
 from repro.ft.runner import RunnerConfig, run
+from repro.kernels.common import resolve_interpret
 from repro.kernels.fused_train import ops as fused_ops
 from repro.models import mrf as mrf_model
 from repro.models.lm import ModelFns
@@ -79,8 +80,9 @@ class EngineConfig:
     max_grad_norm: float | None = None  # None = no clipping (paper setup)
     grad_compress: bool = False
     # fused-pallas knobs: tile_batch=1 is the paper-faithful per-sample SGD
-    # stream; 128 is the MXU-native minibatch mode.  interpret=None
-    # auto-detects: the compiled kernel on TPU, interpreter elsewhere.
+    # stream (interpreter only: compiled tiles are multiples of 8); 128 is
+    # the MXU-native minibatch mode.  interpret=None auto-detects: the
+    # compiled kernel on TPU, interpreter elsewhere.
     tile_batch: int = 128
     interpret: bool | None = None
     donate: bool = True
@@ -111,6 +113,13 @@ class EngineConfig:
                     f"fused-pallas implements optimizers "
                     f"{fused_ops.FUSED_OPTIMIZERS} in-kernel, got "
                     f"{self.optimizer!r}")
+            if (self.tile_batch % fused_ops.TILE_ALIGN
+                    and not resolve_interpret(self.interpret)):
+                raise ValueError(
+                    f"the compiled fused-pallas kernel needs tile_batch to "
+                    f"be a multiple of {fused_ops.TILE_ALIGN}, got "
+                    f"{self.tile_batch} (per-sample tiles run only in the "
+                    f"interpreter)")
 
 
 def _backend_step(fns: ModelFns, cfg: EngineConfig, opt):
@@ -258,6 +267,11 @@ def train(fns: ModelFns, engine_cfg: EngineConfig, runner_cfg: RunnerConfig,  # 
             stream, data_key = stream_and_key()
             batches = make_batch_factory(stream, data_key)
             batch_size = stream.batch_size
+    if engine_cfg.backend == "fused-pallas" and stream is not None:
+        # refuse a batch the compiled kernel cannot tile before the runner
+        # writes its first checkpoint
+        fused_ops.effective_tile(stream.batch_size, engine_cfg.tile_batch,
+                                 interpret=engine_cfg.interpret)
     state0 = init_state(init_key if init_key is not None
                         else jax.random.PRNGKey(0))
 

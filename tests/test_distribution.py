@@ -127,10 +127,10 @@ _SP_SUBPROC = textwrap.dedent("""
     batch_s = specs_mod.batch_specs(cfg, cell)
 
     # the (batch, seq, d_model) activation annotations in the lowered HLO:
-    # shard(h, "batch", "act_seq", None) custom calls on 8x16x64 tensors
-    pat = re.compile(r'@Sharding\\(%\\d+\\) \\{backend_config = "", '
-                     r'mhlo.sharding = "\\{([^}]*)\\}"[^:]*'
-                     r': \\(tensor<8x16x64x')
+    # shard(h, "batch", "act_seq", None) constraints on 8x16x64 tensors,
+    # printed as one mesh-axis set per dim: {"data"}, {"model"}, {}
+    pat = re.compile(r'sdy\\.sharding_constraint %\\d+ <@mesh, \\[([^\\]]*)\\]> '
+                     r': tensor<8x16x64x')
 
     def act_shardings(sp):
         rules = rules_for(mesh, global_batch=cell.global_batch,
@@ -152,8 +152,8 @@ _SP_SUBPROC = textwrap.dedent("""
 def test_sequence_parallel_lowers_act_seq_to_model():
     """ROADMAP open item: ``rules_for(..., sequence_parallel=True)`` must
     map ``act_seq -> model`` all the way into the jitted HLO of a token
-    arch — the (batch, seq, d) activations carry a devices=[4,4,1]
-    sharding (seq over the model axis), which vanishes without sp."""
+    arch — the (batch, seq, d) activations are constrained to batch over
+    ``data`` and seq over ``model``, and seq is unsharded without sp."""
     proc = subprocess.run(
         [sys.executable, "-c", _SP_SUBPROC], capture_output=True, text=True,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -162,12 +162,11 @@ def test_sequence_parallel_lowers_act_seq_to_model():
     out = json.loads(proc.stdout.strip().splitlines()[-1])
     assert out["sp_rule"] == "model" and out["base_rule"] is None
     assert out["sp_shardings"], "no act_seq annotations found in the HLO"
-    assert all(s.startswith("devices=[4,4,1]") for s in out["sp_shardings"])
+    assert all(s == '{"data"}, {"model"}, {}' for s in out["sp_shardings"])
     # without sequence_parallel the seq dim stays unsharded (replicated
-    # across the model axis): 4 batch shards, trailing replication tile
+    # across the model axis): only batch is split, over data
     assert out["base_shardings"], "baseline act annotations vanished"
-    assert all(s.startswith("devices=[4,1,1,4]")
-               for s in out["base_shardings"])
+    assert all(s == '{"data"}, {}, {}' for s in out["base_shardings"])
 
 
 @pytest.mark.parametrize("archs", [

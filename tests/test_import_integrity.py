@@ -23,7 +23,9 @@ def test_all_repro_imports_resolve():
 def test_no_tracked_bytecode():
     """Compiled bytecode must never be committed: it bloats diffs, goes
     stale silently, and once slipped a whole ``__pycache__`` tree into a PR.
-    ``.gitignore`` keeps new ones out; this guards the index itself."""
+    Nor must what test runs and entry points generate: hypothesis's
+    example database and JAX's compilation cache.  ``.gitignore``
+    keeps new ones out; this guards the index itself."""
     try:
         res = subprocess.run(["git", "ls-files"], cwd=REPO_ROOT,
                              capture_output=True, text=True, timeout=60)
@@ -32,14 +34,16 @@ def test_no_tracked_bytecode():
     if res.returncode != 0:
         pytest.skip("not a git checkout")
     tracked = res.stdout.splitlines()
+    generated = {"__pycache__", ".hypothesis", ".jax_cache"}
     offenders = [f for f in tracked
-                 if f.endswith(".pyc") or "__pycache__" in f.split("/")]
+                 if f.endswith(".pyc") or generated & set(f.split("/"))]
     assert offenders == [], (
         f"tracked bytecode files (git rm --cached them): {offenders[:10]}")
     gitignore = REPO_ROOT / ".gitignore"
     assert gitignore.exists() and ".gitignore" in tracked
     rules = gitignore.read_text().splitlines()
-    for required in ("__pycache__/", "*.pyc", ".jaxlint-cache.json"):
+    for required in ("__pycache__/", "*.pyc", ".jaxlint-cache.json",
+                     ".hypothesis/", ".jax_cache/"):
         assert required in rules, f".gitignore is missing {required!r}"
 
 

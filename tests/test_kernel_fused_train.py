@@ -9,7 +9,7 @@ tiles, stream (per-sample) mode, and the QAT fake-quant forward.
 import jax
 import jax.numpy as jnp
 import pytest
-from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import mrf_net
 from repro.kernels.fused_train import ops, ref

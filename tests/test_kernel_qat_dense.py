@@ -5,7 +5,7 @@ Shapes/dtypes swept with hypothesis."""
 import jax
 import jax.numpy as jnp
 import pytest
-from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import mrf_net, qat
 from repro.kernels.qat_dense import ops, ref

@@ -5,7 +5,7 @@ pipeline determinism, and a short end-to-end training run."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import fpga_cost_model as fcm
 from repro.core import metrics, mrf_net, qat

@@ -11,12 +11,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 from _serve_helpers import (N_FRAMES, calibrated_net as _calibrated_net,
-                            features as _features)
+                            features as _features, jitted_float_maps)
 
-from repro.core import mrf_net
-from repro.data.pipeline import denormalize_targets
 from repro.serve.executor import InflightWave, WaveExecutor, plan_tiles
 from repro.serve.queue import RequestQueue, RequestState
 from repro.serve.recon import ReconEngine, ReconRequest
@@ -146,9 +144,10 @@ def test_executor_stages_padded_pool_on_device():
     assert pred.shape == (130, 2)
     # outputs come back already denormalized (ms): the rescale is fused
     # into the jitted forward so retirement never re-touches the device
-    want = np.asarray(denormalize_targets(mrf_net.forward(
-        params, jnp.concatenate([_features(100, 1), _features(30, 2)]))))
-    np.testing.assert_allclose(pred, want, rtol=1e-6)
+    want = jitted_float_maps(
+        params, jnp.concatenate([_features(100, 1), _features(30, 2)]),
+        buckets=(64, 128))
+    np.testing.assert_array_equal(pred, want)
 
 
 def test_pipelined_executor_syncs_once_per_wave(monkeypatch):
@@ -271,9 +270,8 @@ def test_streaming_failure_does_not_poison_the_wave():
     results = engine.drain()
     assert t_ok.state == RequestState.DONE and len(results) == 1
     assert engine.last_wave["n_requests"] == 1
-    want = np.asarray(denormalize_targets(
-        mrf_net.forward(params, ok.features)))
-    np.testing.assert_allclose(t_ok.result.t1_ms, want[:, 0], rtol=1e-6)
+    want = jitted_float_maps(params, ok.features)
+    np.testing.assert_array_equal(t_ok.result.t1_ms, want[:, 0])
 
     # the batch wrapper keeps all-or-nothing semantics: it raises up front,
     # before admitting anything

@@ -15,9 +15,9 @@ import numpy as np
 import pytest
 
 from _serve_helpers import (calibrated_net as _calibrated_net,
-                            features as _features)
+                            features as _features, jitted_float_maps)
 
-from repro.core import mrf_net, qat
+from repro.core import qat
 from repro.data.pipeline import denormalize_targets
 from repro.serve.recon import (DEFAULT_BUCKETS, ReconEngine, ReconRequest,
                                latency_percentiles, plan_tiles)
@@ -90,9 +90,9 @@ def test_float_engine_matches_direct_forward(n_voxels):
     engine = ReconEngine(backend="float", params=params)
     x = _features(n_voxels, seed=n_voxels)
     res, = engine.reconstruct([ReconRequest(features=x)])
-    want = np.asarray(denormalize_targets(mrf_net.forward(params, x)))
-    np.testing.assert_allclose(res.t1_ms, want[:, 0], rtol=1e-6)
-    np.testing.assert_allclose(res.t2_ms, want[:, 1], rtol=1e-6)
+    want = jitted_float_maps(params, x)
+    np.testing.assert_array_equal(res.t1_ms, want[:, 0])
+    np.testing.assert_array_equal(res.t2_ms, want[:, 1])
     assert res.n_voxels == n_voxels and res.latency_s > 0
 
 
@@ -154,8 +154,8 @@ def test_masked_reassembly_and_background():
     res, = engine.reconstruct([ReconRequest(features=x, mask=mask)])
     assert res.t1_ms.shape == mask.shape
     assert np.all(res.t1_ms[~mask] == 0) and np.all(res.t2_ms[~mask] == 0)
-    want = np.asarray(denormalize_targets(mrf_net.forward(params, x)))
-    np.testing.assert_allclose(res.t1_ms[mask], want[:, 0], rtol=1e-6)
+    want = jitted_float_maps(params, x)
+    np.testing.assert_array_equal(res.t1_ms[mask], want[:, 0])
 
 
 def test_request_validation():

@@ -199,6 +199,14 @@ def test_engine_rejects_configs_fused_cannot_honor():
         engine.EngineConfig(optimizer="rmsprop")  # any backend: whitelist
     with pytest.raises(ValueError, match="sgd"):
         make_engine_step(lr=1e-2, optimizer="rmsprop")
+    # Mosaic tiles blocks by 8 rows: the compiled kernel refuses per-sample
+    # and other unaligned tiles at config time; the interpreter takes them
+    with pytest.raises(ValueError, match="multiple of 8"):
+        engine.EngineConfig(backend="fused-pallas", tile_batch=1,
+                            interpret=False)
+    engine.EngineConfig(backend="fused-pallas", tile_batch=1, interpret=True)
+    engine.EngineConfig(backend="fused-pallas", tile_batch=24,
+                        interpret=False)
 
     fused = lambda p, o, a, b: (p, o, a, {})
     with pytest.raises(ValueError, match="microbatches"):
@@ -219,6 +227,13 @@ def test_fused_tile_adapts_to_awkward_batch():
     assert effective_tile(97, 128) == 97    # prime under the ceiling: 1 tile
     assert effective_tile(254, 128) == 127  # 2*127 -> the big prime factor
     assert effective_tile(96, 36) == 32     # largest divisor <= ceiling
+    # compiled: the largest multiple of 8 that divides, or a ValueError
+    assert effective_tile(192, 128, interpret=False) == 96
+    assert effective_tile(96, 36, interpret=False) == 32
+    assert effective_tile(24, 128, interpret=False) == 24
+    for batch, tile in ((100, 128), (13, 8), (254, 128), (64, 4)):
+        with pytest.raises(ValueError, match="multiple of 8"):
+            effective_tile(batch, tile, interpret=False)
     cfg = get_smoke("mrf-fpga")
     fns = registry.build(cfg)
     stream = MRFSampleStream(seq=default_sequence(cfg.mrf_n_frames),
@@ -269,8 +284,34 @@ def test_fused_multi_tile_is_sequential_sgd():
 # the launcher, end to end (checkpointing runner, all three backends)
 # --------------------------------------------------------------------------
 
+@pytest.fixture
+def restore_cache_dir():
+    """The launchers point JAX's persistent compilation cache at the
+    checkout; put the process-wide setting back for the next test."""
+    before = jax.config.jax_compilation_cache_dir
+    yield before
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+@pytest.mark.parametrize("env_set", [False, True])
+def test_compile_cache_location(env_set, monkeypatch, tmp_path,
+                                restore_cache_dir):
+    """With JAX_COMPILATION_CACHE_DIR set the cache is JAX's to place (it
+    reads the variable itself); without it, one fixed directory at the
+    root of the checkout (git ignores it: test_no_tracked_bytecode)."""
+    from repro.launch import CHECKOUT_CACHE_DIR, enable_compile_cache
+    if env_set:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    enable_compile_cache()
+    want = restore_cache_dir if env_set else str(CHECKOUT_CACHE_DIR)
+    assert jax.config.jax_compilation_cache_dir == want
+    assert (CHECKOUT_CACHE_DIR.parent / "src" / "repro" / "launch").is_dir()
+
+
 @pytest.mark.parametrize("backend", ["float", "qat-int8", "fused-pallas"])
-def test_launcher_smoke_all_backends(backend, tmp_path):
+def test_launcher_smoke_all_backends(backend, tmp_path, restore_cache_dir):
     from repro.launch.train import main
     rc = main(["--arch", "mrf-fpga", "--smoke", "--steps", "3",
                "--batch", "128", "--backend", backend, "--lr", "1e-3",
