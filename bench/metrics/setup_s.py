@@ -1,0 +1,5 @@
+"""Set-up time: from process start to the window, compiles included."""
+
+
+def read(run):
+    return run.setup_s
