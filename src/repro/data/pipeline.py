@@ -85,8 +85,10 @@ def batch_at(stream: MRFSampleStream, key: jax.Array, step) -> dict:
     loop can synthesize batches *inside* ``lax.scan`` (zero steady-state
     host->device transfers) and draw bit-identical data to the host path.
     ``make_batch_factory`` routes through here so the two can never diverge.
+    Its ops carry the name scope ``simulate`` in a compiled program.
     """
-    x, y = sample_batch(stream, jax.random.fold_in(key, step))
+    with jax.named_scope("simulate"):
+        x, y = sample_batch(stream, jax.random.fold_in(key, step))
     return {"x": x, "y": y}
 
 

@@ -15,6 +15,12 @@ Async mode: device->host transfer happens synchronously (cheap), file IO on a
 background thread so the train loop isn't blocked (the standard async-ckpt
 split).  ``CheckpointManager`` keeps the last K checkpoints and handles
 resume-from-latest.
+
+Spans (``repro.obs``): ``ckpt.snapshot`` is the synchronous part of a save
+(counting ``ckpt.saves``, ``ckpt.bytes`` and a ``d2h`` per host copy),
+``ckpt.flush`` the file writes, rename and clean-up (on the worker thread
+when async), ``ckpt.wait`` a wait on the previous flush and
+``ckpt.restore`` a resume.
 """
 
 from __future__ import annotations
@@ -28,6 +34,8 @@ from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import numpy as np
+
+from repro import obs
 
 _SMALL = 1 << 20  # leaves below 1 MiB are stored as single global arrays
 
@@ -47,22 +55,52 @@ def _index_to_json(idx, shape):
 
 
 def save_state(state, directory, step: int, *, async_io: bool = True,
-               _executor=ThreadPoolExecutor(max_workers=2)):
-    """Save a pytree of (possibly sharded) jax arrays. Returns a wait() fn."""
+               on_flushed=None, _executor=ThreadPoolExecutor(max_workers=2)):
+    """Save a pytree of (possibly sharded) jax arrays. Returns a wait() fn.
+    ``on_flushed()``, if given, runs once the checkpoint has landed, as part
+    of the flush."""
     directory = pathlib.Path(directory)
     tmp = directory / f".tmp_step_{step}"
     final = directory / f"step_{step}"
-    if tmp.exists():
-        shutil.rmtree(tmp)
-    tmp.mkdir(parents=True)
+    with obs.span("ckpt.snapshot", step=step):
+        if tmp.exists():
+            shutil.rmtree(tmp)
+        tmp.mkdir(parents=True)
+        manifest, work = _snapshot(state, tmp, step)
+    obs.count("ckpt.saves")
+    obs.count("ckpt.bytes", sum(host.nbytes for _path, host in work))
+    obs.count("d2h", len(work))
 
+    def flush():
+        with obs.span("ckpt.flush", step=step):
+            for path, host in work:
+                path.parent.mkdir(parents=True, exist_ok=True)
+                np.save(path, host)
+            (tmp / "manifest.json").write_text(json.dumps(manifest))
+            if final.exists():
+                shutil.rmtree(final)
+            os.rename(tmp, final)
+            latest_tmp = directory / ".LATEST.tmp"
+            latest_tmp.write_text(str(step))
+            os.replace(latest_tmp, directory / "LATEST")
+            if on_flushed is not None:
+                on_flushed()
+
+    if async_io:
+        fut = _executor.submit(flush)
+        return fut.result  # wait() function
+    flush()
+    return lambda: None
+
+
+def _snapshot(state, tmp, step: int):
+    """(manifest, [(file path, host array)]): the state copied to the host,
+    one array per leaf, or per distinct shard of a large sharded leaf."""
     leaves, treedef = _leaf_paths(state)
     # tree structure is carried by the restore-side `like` tree (restore_state
     # asserts leaf counts); record the repr for human debugging only.
     manifest = {"step": step, "treedef_repr": str(treedef)[:2000],
                 "n_leaves": len(leaves), "leaves": []}
-
-    # synchronous device->host snapshot; file IO deferred to the worker
     work = []
     for i, leaf in enumerate(leaves):
         arr = leaf
@@ -72,11 +110,11 @@ def save_state(state, directory, step: int, *, async_io: bool = True,
                 "shards": []}
         if hasattr(arr, "addressable_shards") and arr.nbytes > _SMALL:
             for j, shard in enumerate(arr.addressable_shards):
-                host = np.asarray(shard.data)
                 idx = _index_to_json(shard.index, arr.shape)
                 # skip duplicate replicas: only save the first owner
                 if any(s["index"] == idx for s in info["shards"]):
                     continue
+                host = np.asarray(shard.data)
                 fn = f"leaf_{i}/shard_{len(info['shards'])}.npy"
                 info["shards"].append({"file": fn, "index": idx})
                 work.append((tmp / fn, host))
@@ -86,24 +124,7 @@ def save_state(state, directory, step: int, *, async_io: bool = True,
             info["file"] = fn
             work.append((tmp / fn, host))
         manifest["leaves"].append(info)
-
-    def flush():
-        for path, host in work:
-            path.parent.mkdir(parents=True, exist_ok=True)
-            np.save(path, host)
-        (tmp / "manifest.json").write_text(json.dumps(manifest))
-        if final.exists():
-            shutil.rmtree(final)
-        os.rename(tmp, final)
-        latest_tmp = directory / ".LATEST.tmp"
-        latest_tmp.write_text(str(step))
-        os.replace(latest_tmp, directory / "LATEST")
-
-    if async_io:
-        fut = _executor.submit(flush)
-        return fut.result  # wait() function
-    flush()
-    return lambda: None
+    return manifest, work
 
 
 def latest_step(directory) -> int | None:
@@ -161,19 +182,16 @@ class CheckpointManager:
         if not force and step % self.every:
             return False
         self.wait()
-        inner = save_state(state, self.dir, step, async_io=True)
-
-        def finish():  # GC only after the rename landed
-            inner()
-            self._gc()
-
-        self._pending = finish
+        # GC only after the rename landed, on the flush's thread
+        self._pending = save_state(state, self.dir, step, async_io=True,
+                                   on_flushed=self._gc)
         return True
 
     def wait(self):
         with self._lock:
             if self._pending is not None:
-                self._pending()
+                with obs.span("ckpt.wait"):
+                    self._pending()
                 self._pending = None
 
     def _gc(self):
@@ -183,7 +201,9 @@ class CheckpointManager:
             shutil.rmtree(self.dir / f"step_{s}", ignore_errors=True)
 
     def restore_latest(self, like, shardings=None):
-        step = latest_step(self.dir)
-        if step is None:
-            return None, 0
-        return restore_state(like, self.dir, step, shardings=shardings), step
+        with obs.span("ckpt.restore"):
+            step = latest_step(self.dir)
+            if step is None:
+                return None, 0
+            return (restore_state(like, self.dir, step, shardings=shardings),
+                    step)

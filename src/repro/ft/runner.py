@@ -15,8 +15,12 @@ Chunked (``chunk_steps>1`` + a ``chunk_fn``): ``chunk_fn(state, start, n)``
 runs ``n`` steps in one jitted ``lax.scan`` dispatch (batches synthesized
 on-device — see train/engine.build_chunked) and returns per-step metrics
 stacked ``(n, ...)``.  The loop dispatches chunk N+1 *before* syncing chunk
-N's metrics, so the device never idles on the host fetch; metrics cross to
-the host once per chunk.  Chunk ends are clipped to checkpoint boundaries,
+N's metrics, so between checkpoints the host fetch overlaps the next chunk's
+compute; metrics cross to the host once per chunk.  At a checkpoint
+boundary the loop retires the in-flight chunk and then snapshots the state
+with nothing queued, so the device idles for the fetch, the wait on the
+previous checkpoint's flush and the snapshot, until the next dispatch.
+Chunk ends are clipped to checkpoint boundaries,
 ``total_steps`` (the final ragged chunk runs at its own static length), and
 the fault-injection step, so checkpoints land exactly where the stepwise
 loop would put them and a resume starts from any chunk boundary.  The
@@ -30,6 +34,12 @@ deterministically on CPU: the loop "crashes" at a chosen step, then the
 restart resumes from the latest checkpoint and must reach the same final
 state as an uninterrupted run (tests/test_fault_tolerance.py,
 tests/test_chunked_training.py).
+
+Spans and counters (``repro.obs``): ``runner.dispatch`` around each launch
+(counting ``runner.chunks`` and ``runner.steps``), ``runner.retire`` around
+retiring a chunk with ``runner.fetch`` nested around its metrics' host
+fetch (counting ``d2h``), and ``runner.sync`` around the waits for the
+device; the checkpoint manager spans its own restore, wait and save.
 """
 
 from __future__ import annotations
@@ -40,6 +50,7 @@ from typing import Any, Callable
 
 import jax
 
+from repro import obs
 from repro.ft.checkpoint import CheckpointManager
 from repro.ft.straggler import StragglerMonitor
 
@@ -130,9 +141,13 @@ def _stepwise_loop(train_step, state, step, batches, cfg, mgr, monitor, *,
         t0 = time.perf_counter()
         if fault_live and step == cfg.inject_fault_at:
             return state, None
-        state, metrics = train_step(state, batch)
+        with obs.span("runner.dispatch", step=step, n=1):
+            state, metrics = train_step(state, batch)
+        obs.count("runner.chunks")
+        obs.count("runner.steps")
         if sync_each_step:
-            jax.block_until_ready(metrics["loss"])  # jaxlint: disable=HOSTSYNC -- opt-in sync_each_step mode exists to measure true per-step latency
+            with obs.span("runner.sync"):
+                jax.block_until_ready(metrics["loss"])  # jaxlint: disable=HOSTSYNC -- opt-in sync_each_step mode exists to measure true per-step latency
         # without a callback, dt is dispatch time only (async steps); the
         # straggler EWMA then watches dispatch latency, documented above
         dt = time.perf_counter() - t0
@@ -143,7 +158,8 @@ def _stepwise_loop(train_step, state, step, batches, cfg, mgr, monitor, *,
         mgr.maybe_save(state, step)  # device->host snapshot = a sync point
         if on_metrics:
             on_metrics(step, metrics, dt)
-    jax.block_until_ready(state)  # jaxlint: disable=HOSTSYNC -- loop exit: the promised final sync, once per run
+    with obs.span("runner.sync"):
+        jax.block_until_ready(state)  # jaxlint: disable=HOSTSYNC -- loop exit: the promised final sync, once per run
     return state, step
 
 
@@ -158,20 +174,25 @@ def _chunked_loop(chunk_fn, state, step, cfg, mgr, monitor, *, on_metrics,
         """Block on a chunk's stacked metrics, fan them out per step."""
         nonlocal retired_at
         c_start, n, metrics, t0 = chunk
-        host = jax.device_get(metrics)  # ONE host fetch for n steps
-        now = time.perf_counter()
-        # a chunk dispatched while its predecessor was still computing only
-        # *started* when the predecessor retired — clamp so overlapped wall
-        # time isn't double-counted in dt / the straggler EWMA
-        dt = now - max(t0, retired_at)
-        retired_at = now
-        # per-step normalized: boundary-clipped chunks vary in length, and
-        # the EWMA must compare like with like (and with stepwise runs)
-        action = monitor.update(dt / n)
-        if on_metrics:
-            for i in range(n):
-                on_metrics(c_start + i + 1,
-                           jax.tree.map(lambda m: m[i], host), dt / n)
+        with obs.span("runner.retire", step=c_start, n=n):
+            with obs.span("runner.fetch"):
+                host = jax.device_get(metrics)  # ONE host fetch for n steps
+            obs.count("d2h")
+            now = time.perf_counter()
+            # a chunk dispatched while its predecessor was still computing
+            # only *started* when the predecessor retired — clamp so
+            # overlapped wall time isn't double-counted in dt / the
+            # straggler EWMA
+            dt = now - max(t0, retired_at)
+            retired_at = now
+            # per-step normalized: boundary-clipped chunks vary in length,
+            # and the EWMA must compare like with like (and with stepwise
+            # runs)
+            action = monitor.update(dt / n)
+            if on_metrics:
+                for i in range(n):
+                    on_metrics(c_start + i + 1,
+                               jax.tree.map(lambda m: m[i], host), dt / n)
         return action
 
     while step < cfg.total_steps:
@@ -184,7 +205,10 @@ def _chunked_loop(chunk_fn, state, step, cfg, mgr, monitor, *, on_metrics,
         if fault_live and step < cfg.inject_fault_at:
             n = min(n, cfg.inject_fault_at - step)
         t0 = time.perf_counter()
-        new_state, metrics = chunk_fn(state, step, n)  # async dispatch
+        with obs.span("runner.dispatch", step=step, n=n):
+            new_state, metrics = chunk_fn(state, step, n)  # async dispatch
+        obs.count("runner.chunks")
+        obs.count("runner.steps", n)
         prev, inflight = inflight, (step, n, metrics, t0)
         state, step = new_state, step + n
         if prev is not None:  # overlap: chunk N computes while N-1 retires
@@ -198,6 +222,7 @@ def _chunked_loop(chunk_fn, state, step, cfg, mgr, monitor, *, on_metrics,
             mgr.maybe_save(state, step)
     if inflight is not None:
         retire(inflight)
-    jax.block_until_ready(state)  # jaxlint: disable=HOSTSYNC -- chunked-loop exit: one final sync after the last chunk retires
+    with obs.span("runner.sync"):
+        jax.block_until_ready(state)  # jaxlint: disable=HOSTSYNC -- chunked-loop exit: one final sync after the last chunk retires
     mgr.maybe_save(state, step)
     return state, step

@@ -6,10 +6,15 @@ The zero padding is *self-preserving*: padded weight rows/cols and biases are
 zero, padded activations stay exactly 0 through ReLU, and every padded
 gradient entry is a product with one of those zeros — so the unpadded result
 equals the unpadded math (asserted against ref.py in the tests).
+
+Padding and unpadding carry the name scope ``stage`` in a compiled program,
+as the engine's staging of a chunk's batches does, so that device time
+outside the kernel can be told apart from the batches' synthesis.
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels.common import resolve_interpret
@@ -25,6 +30,7 @@ from repro.optim.optimizers import AdamState
 FUSED_OPTIMIZERS = ("sgd", "adam")
 
 
+@jax.named_scope("stage")
 def pad_params(params):
     """Ragged [{'w','b'}] -> ((L,PAD,PAD), (L,PAD)) zero-padded stacks."""
     n_layers = len(params)
@@ -38,12 +44,19 @@ def pad_params(params):
     return w, b
 
 
+@jax.named_scope("stage")
 def unpad_params(w_pad, b_pad, like):
     out = []
     for l, layer in enumerate(like):
         i, o = layer["w"].shape
         out.append({"w": w_pad[l, :i, :o], "b": b_pad[l, :o]})
     return out
+
+
+@jax.named_scope("stage")
+def _pad_features(a):
+    """(rows, d) -> (rows, PAD), zero-padded: the kernel's sample layout."""
+    return jnp.zeros((a.shape[0], PAD), jnp.float32).at[:, :a.shape[1]].set(a)
 
 
 def fused_train_step(params, x, y, *, lr: float, tile_batch: int = 128,
@@ -54,8 +67,7 @@ def fused_train_step(params, x, y, *, lr: float, tile_batch: int = 128,
     out_dim = y.shape[-1]
     assert d_in <= PAD, f"feature dim {d_in} > PAD={PAD}"
     assert batch % tile_batch == 0, (batch, tile_batch)
-    x_pad = jnp.zeros((batch, PAD), jnp.float32).at[:, :d_in].set(x)
-    y_pad = jnp.zeros((batch, PAD), jnp.float32).at[:, :out_dim].set(y)
+    x_pad, y_pad = _pad_features(x), _pad_features(y)
     w_pad, b_pad = pad_params(params)
     w_new, b_new, losses = fused_train_call(
         x_pad, y_pad, w_pad, b_pad, n_layers=len(params), out_dim=out_dim,
@@ -116,8 +128,7 @@ def fused_train_multistep(params, opt_state, x, y, *, n_steps: int, lr: float,
             f"MAX_LAUNCH_TILES={MAX_LAUNCH_TILES} (per-tile values live in "
             f"SMEM): use fewer chunk steps or larger tiles")
     assert d_in <= PAD, f"feature dim {d_in} > PAD={PAD}"
-    x_pad = jnp.zeros((total, PAD), jnp.float32).at[:, :d_in].set(x)
-    y_pad = jnp.zeros((total, PAD), jnp.float32).at[:, :out_dim].set(y)
+    x_pad, y_pad = _pad_features(x), _pad_features(y)
     w_pad, b_pad = pad_params(params)
     if optimizer == "sgd":
         w_new, b_new, tile_losses = fused_train_multistep_call(

@@ -182,12 +182,14 @@ def _make_fused_chunk(cfg: EngineConfig, stream: MRFSampleStream,
     (``n`` is static, so the Python staging loop traces once per chunk
     length and the seekable-by-step restart semantics survive unchanged).
     Per-step metrics come back as the kernel's ``(n,)`` loss trace —
-    element-identical to ``n`` stepwise fused calls.
+    element-identical to ``n`` stepwise fused calls.  The staging ops carry
+    the name scope ``stage``, the batches' own ``simulate``.
     """
     def chunk_step(state: TrainState, start, n: int):
         staged = [batch_at(stream, data_key, start + k) for k in range(n)]
-        x = jnp.concatenate([b["x"] for b in staged])
-        y = jnp.concatenate([b["y"] for b in staged])
+        with jax.named_scope("stage"):
+            x = jnp.concatenate([b["x"] for b in staged])
+            y = jnp.concatenate([b["y"] for b in staged])
         new_params, new_opt, losses = fused_ops.fused_train_multistep(
             state.params, state.opt_state, x, y, n_steps=n, lr=cfg.lr,
             optimizer=cfg.optimizer, tile_batch=cfg.tile_batch,
