@@ -2,22 +2,35 @@
 
 Layout:
     <dir>/step_<N>/
-        manifest.json            tree structure + leaf shapes/dtypes
-        leaf_<i>/shard_<j>.npy   one file per addressable shard
-        leaf_<i>.npy             (small leaves: single global array)
+        manifest.json            tree structure + per-leaf shape/dtype and
+                                 where the leaf's bytes are
+        leaves.bin               every small leaf, raw bytes, each at an
+                                 offset aligned to 64 (manifest: ``offset``,
+                                 ``nbytes``)
+        leaf_<i>/shard_<j>.npy   large leaves: one file per distinct
+                                 addressable shard (manifest: ``shards``)
     <dir>/LATEST                 atomic pointer (tmp+rename)
+
+A leaf is small when it is at most ``_SMALL`` bytes or has no
+``addressable_shards``.  The small leaves of a save cross to the host in one
+``jax.device_get`` (every copy started before any is waited on) and land in
+one file with one write; a save's file count does not grow with them.
+Checkpoints written one ``leaf_<i>.npy`` per small leaf (manifest:
+``file``), as older versions did, still restore.
 
 Each shard file records its *global index* (slices into the global array), so
 restore can reassemble onto ANY mesh/sharding — the elastic-scaling property:
 a checkpoint from a 256-chip run restores onto 512 chips or 8 (DESIGN.md §5).
 
-Async mode: device->host transfer happens synchronously (cheap), file IO on a
+Async mode: the device->host copy and the manifest happen synchronously; all
+file IO (the temporary directory, writes, rename, ``LATEST``, clean-up) on a
 background thread so the train loop isn't blocked (the standard async-ckpt
 split).  ``CheckpointManager`` keeps the last K checkpoints and handles
 resume-from-latest.
 
 Spans (``repro.obs``): ``ckpt.snapshot`` is the synchronous part of a save
-(counting ``ckpt.saves``, ``ckpt.bytes`` and a ``d2h`` per host copy),
+(counting ``ckpt.saves``, ``ckpt.bytes``, ``ckpt.packed_leaves`` and a
+``d2h`` per host copy: one for the small leaves, one per large shard),
 ``ckpt.flush`` the file writes, rename and clean-up (on the worker thread
 when async), ``ckpt.wait`` a wait on the previous flush and
 ``ckpt.restore`` a resume.
@@ -33,11 +46,14 @@ import threading
 from concurrent.futures import ThreadPoolExecutor
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
 
-_SMALL = 1 << 20  # leaves below 1 MiB are stored as single global arrays
+_SMALL = 1 << 20  # leaves up to 1 MiB go into the pack as global arrays
+PACK = "leaves.bin"
+_ALIGN = 64  # byte alignment of each leaf in the pack
 
 
 def _leaf_paths(tree):
@@ -63,18 +79,22 @@ def save_state(state, directory, step: int, *, async_io: bool = True,
     tmp = directory / f".tmp_step_{step}"
     final = directory / f"step_{step}"
     with obs.span("ckpt.snapshot", step=step):
-        if tmp.exists():
-            shutil.rmtree(tmp)
-        tmp.mkdir(parents=True)
-        manifest, work = _snapshot(state, tmp, step)
+        manifest, packed, shards = _snapshot(state, step)
     obs.count("ckpt.saves")
-    obs.count("ckpt.bytes", sum(host.nbytes for _path, host in work))
-    obs.count("d2h", len(work))
+    obs.count("ckpt.bytes", sum(h.nbytes for _off, h in packed)
+              + sum(h.nbytes for _fn, h in shards))
+    obs.count("ckpt.packed_leaves", len(packed))
+    obs.count("d2h", (1 if packed else 0) + len(shards))
 
     def flush():
         with obs.span("ckpt.flush", step=step):
-            for path, host in work:
-                path.parent.mkdir(parents=True, exist_ok=True)
+            if tmp.exists():
+                shutil.rmtree(tmp)
+            tmp.mkdir(parents=True)
+            (tmp / PACK).write_bytes(_pack(packed, manifest["pack_nbytes"]))
+            for fn, host in shards:
+                path = tmp / fn
+                path.parent.mkdir(exist_ok=True)
                 np.save(path, host)
             (tmp / "manifest.json").write_text(json.dumps(manifest))
             if final.exists():
@@ -93,38 +113,53 @@ def save_state(state, directory, step: int, *, async_io: bool = True,
     return lambda: None
 
 
-def _snapshot(state, tmp, step: int):
-    """(manifest, [(file path, host array)]): the state copied to the host,
-    one array per leaf, or per distinct shard of a large sharded leaf."""
+def _snapshot(state, step: int):
+    """(manifest, [(pack offset, host array)], [(shard file, host array)]):
+    the state copied to the host, the small leaves in one fetch, a large
+    sharded leaf per distinct shard."""
     leaves, treedef = _leaf_paths(state)
     # tree structure is carried by the restore-side `like` tree (restore_state
     # asserts leaf counts); record the repr for human debugging only.
     manifest = {"step": step, "treedef_repr": str(treedef)[:2000],
-                "n_leaves": len(leaves), "leaves": []}
-    work = []
+                "n_leaves": len(leaves), "pack": PACK, "leaves": []}
+    small = [i for i, leaf in enumerate(leaves)
+             if not (hasattr(leaf, "addressable_shards")
+                     and leaf.nbytes > _SMALL)]
+    # one device_get starts every leaf's copy before it waits on any
+    fetched = jax.device_get([leaves[i] for i in small])
+    hosts = {i: np.asarray(h) for i, h in zip(small, fetched)}
+    packed, shards, offset = [], [], 0
     for i, leaf in enumerate(leaves):
-        arr = leaf
-        info = {"shape": list(np.shape(arr)),
-                "dtype": str(np.asarray(jax.tree.leaves(arr)[0]).dtype)
-                if not hasattr(arr, "dtype") else str(arr.dtype),
+        if i in hosts:
+            host = hosts[i]
+            manifest["leaves"].append(
+                {"shape": list(host.shape), "dtype": str(host.dtype),
+                 "offset": offset, "nbytes": host.nbytes})
+            packed.append((offset, host))
+            offset += -(-host.nbytes // _ALIGN) * _ALIGN
+            continue
+        info = {"shape": list(leaf.shape), "dtype": str(leaf.dtype),
                 "shards": []}
-        if hasattr(arr, "addressable_shards") and arr.nbytes > _SMALL:
-            for j, shard in enumerate(arr.addressable_shards):
-                idx = _index_to_json(shard.index, arr.shape)
-                # skip duplicate replicas: only save the first owner
-                if any(s["index"] == idx for s in info["shards"]):
-                    continue
-                host = np.asarray(shard.data)
-                fn = f"leaf_{i}/shard_{len(info['shards'])}.npy"
-                info["shards"].append({"file": fn, "index": idx})
-                work.append((tmp / fn, host))
-        else:
-            host = np.asarray(jax.device_get(arr))
-            fn = f"leaf_{i}.npy"
-            info["file"] = fn
-            work.append((tmp / fn, host))
+        for shard in leaf.addressable_shards:
+            idx = _index_to_json(shard.index, leaf.shape)
+            # skip duplicate replicas: only save the first owner
+            if any(s["index"] == idx for s in info["shards"]):
+                continue
+            fn = f"leaf_{i}/shard_{len(info['shards'])}.npy"
+            info["shards"].append({"file": fn, "index": idx})
+            shards.append((fn, np.asarray(shard.data)))
         manifest["leaves"].append(info)
-    return manifest, work
+    manifest["pack_nbytes"] = offset
+    return manifest, packed, shards
+
+
+def _pack(packed, nbytes: int) -> np.ndarray:
+    """The pack file's bytes: each small leaf's at its offset."""
+    buf = np.zeros(nbytes, np.uint8)
+    for offset, host in packed:
+        raw = host.reshape(-1).view(np.uint8)
+        buf[offset:offset + raw.size] = raw
+    return buf
 
 
 def latest_step(directory) -> int | None:
@@ -149,13 +184,18 @@ def restore_state(like, directory, step: int | None = None, *,
     assert len(leaves) == manifest["n_leaves"], "tree structure mismatch"
     shard_leaves = (jax.tree.leaves(shardings) if shardings is not None
                     else [None] * len(leaves))
+    pack = (d / manifest["pack"]).read_bytes() if "pack" in manifest else None
 
     out = []
     for i, (leaf, info) in enumerate(zip(leaves, manifest["leaves"])):
-        if "file" in info:
+        dtype = jnp.dtype(info["dtype"])
+        if "offset" in info:
+            host = np.frombuffer(pack, dtype, info["nbytes"] // dtype.itemsize,
+                                 info["offset"]).reshape(info["shape"])
+        elif "file" in info:  # one .npy per small leaf (older checkpoints)
             host = np.load(d / info["file"])
         else:
-            host = np.zeros(info["shape"], dtype=info["dtype"])
+            host = np.zeros(info["shape"], dtype=dtype)
             for s in info["shards"]:
                 idx = tuple(slice(a, b) for a, b in s["index"])
                 host[idx] = np.load(d / s["file"])

@@ -27,8 +27,9 @@ SPANS = (
 )
 _SPAN_SET = frozenset(SPANS)
 
-# runner.chunks, runner.steps, d2h (device->host fetches), ckpt.saves,
-# ckpt.bytes (host bytes snapshotted)
+# runner.chunks, runner.steps, d2h (device->host fetches: a checkpoint's
+# small leaves make one), ckpt.saves, ckpt.bytes (host bytes snapshotted),
+# ckpt.packed_leaves (small leaves written into a checkpoint's one pack file)
 COUNTS: collections.Counter = collections.Counter()
 
 

@@ -79,7 +79,9 @@ def test_chunked_run_spans_and_counts(tmp_path):
         "runner.chunks": len(chunks), "runner.steps": TOTAL,
         "ckpt.saves": saves,
         "ckpt.bytes": saves * sum(a.nbytes for a in leaves),
-        "d2h": len(chunks) + len(leaves) * saves}
+        # a save's small leaves cross to the host in one fetch
+        "ckpt.packed_leaves": len(leaves) * saves,
+        "d2h": len(chunks) + saves}
 
     seen = {name for events in lines.values() for name, _s, _e in events}
     assert seen == set(obs.SPANS)
