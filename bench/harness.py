@@ -4,11 +4,15 @@ the correctness comparison and the result line.
 The generator kind of a cell comes from its traffic file (``kind``) and is
 the class ``CELL`` of ``bench/kinds/<kind>.py``; the metrics it reports
 come from ``BENCHMARK.json`` and are read by the reader files in
-``bench/metrics``.  Nothing here names a cell or a kind.
+``bench/metrics``.  Nothing here names a cell, a kind or a model family:
+the work of a cell at its real widths is its kind object's to give
+(``Run.kind``), and the program's counters and spans are read by the
+names ``repro.obs`` lists.
 """
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import glob
 import importlib.util
@@ -19,7 +23,8 @@ import time
 
 import jax
 
-from bench import peaks, spec, trace, work
+from bench import peaks, spec, trace
+from repro import obs
 
 METRICS_DIR = spec.BENCH / "metrics"
 # a program lowered inside the window is a compile the warm-up missed
@@ -28,13 +33,21 @@ _LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 
 @dataclasses.dataclass
 class Run:
-    """What a metric reader sees of one run."""
+    """What a metric reader sees of one run, and what its result line is
+    made of."""
     cell: spec.Cell
-    sizes: tuple
+    # the cell's kind object, its set-up done; a reader of work at real
+    # widths asks it (the training kind: ops_per_sample, kernel_work)
+    kind: object
     peaks: dict
     setup_s: float
+    # the kind's counters over the window, beside the program's counter
+    # deltas (repro.obs.COUNTS) under their own names
     counters: dict
     trace: trace.Reduced | None
+    device: dict = dataclasses.field(default_factory=dict)
+    numbers: dict = dataclasses.field(default_factory=dict)
+    lowered: int = 0             # programs lowered inside the window
 
 
 def enable_cache() -> None:
@@ -84,12 +97,12 @@ def _finite(x):
     return x if math.isfinite(x) else str(x)
 
 
-def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
-        t_start: float) -> tuple:
-    """(result line, programs lowered inside the window, every number the
-    kind computed against the reference) of one run.  The line's last key,
-    ``checks``, holds each number compared beside its limit.  ``t_start`` is when the process started, so that ``setup_s``
-    counts imports and JAX's start."""
+def measure(cell: spec.Cell, seed: int, seconds: float, traced: bool,
+            t_start: float, keep_trace=None) -> Run:
+    """Set-up, the window (profiled if ``traced``, the trace reduced and
+    copied to ``keep_trace`` if given), and the comparison with the
+    reference.  ``t_start`` is when the process started, so that
+    ``setup_s`` counts imports and JAX's start."""
     devices = jax.devices()
     dev = devices[0]
     table = peaks.peaks_for(dev.device_kind)
@@ -107,6 +120,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
             opts.python_tracer_level = 0
             jax.profiler.start_trace(tdir, profiler_options=opts)
         lowerings.on = True
+        before = collections.Counter(obs.COUNTS)
         try:
             with jax.profiler.TraceAnnotation("window"):
                 counters = c.window()
@@ -114,39 +128,62 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
             lowerings.on = False
             if traced:
                 jax.profiler.stop_trace()
+        deltas = {k: v - before[k] for k, v in obs.COUNTS.items()
+                  if v != before[k]}
+        counters = {**deltas, **counters}
         stats = dev.memory_stats() or {}
-        mem_peak = int(stats.get("peak_bytes_in_use", 0))
         reduced = None
         if traced:
             files = glob.glob(f"{tdir}/**/*.xplane.pb", recursive=True)
-            reduced = trace.reduce_xplane(files[0])
+            if keep_trace:
+                shutil.copy(files[0], keep_trace)
+            reduced = trace.reduce_xplane(files[0], spans=obs.SPANS)
     finally:
         if tdir:
             shutil.rmtree(tdir, ignore_errors=True)
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(devices),
+              "memory_peak_bytes": int(stats.get("peak_bytes_in_use", 0))}
     # the kind computes every number it can; the cell's file says which
     # are compared, and their limits
-    numbers = c.check()
+    return Run(cell=cell, kind=c, peaks=table, setup_s=setup_s,
+               counters=counters, trace=reduced, device=device,
+               numbers=c.check(), lowered=lowerings.n)
+
+
+def line(r: Run) -> dict:
+    """The result line of run ``r``: its cell's end-to-end metrics, or
+    traced its per-layer metrics, each from its reader, and last,
+    ``checks``, each number compared beside its limit."""
+    cell, counters, numbers = r.cell, r.counters, r.numbers
     checks = {k: {"value": _finite(numbers[k]), "limit": lim}
               for k, lim in cell.limits.items()}
     correct = (counters["failed"] == 0
                and all(math.isfinite(numbers[k]) and numbers[k] <= lim
                        for k, lim in cell.limits.items()))
-    r = Run(cell=cell, sizes=work.layer_sizes(cell.config), peaks=table,
-            setup_s=setup_s, counters=counters, trace=reduced)
     metrics = {}
+    traced = r.trace is not None
     for m in (cell.per_layer if traced else cell.end_to_end):
         value = reader(m["name"])(r)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-    device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(devices), "memory_peak_bytes": mem_peak}
+    device = dict(r.device)
     result = {"correct": bool(correct), "attempted": int(counters["attempted"]),
               "failed": int(counters["failed"]), "metrics": metrics,
               "device": device}
     if traced:
-        device["busy_s"] = reduced.busy_s
-        device["window_s"] = reduced.window_s
-        result["breakdown"] = {"device_ops": reduced.top_ops(),
-                               "idle_gaps": reduced.top_idle()}
+        device["busy_s"] = r.trace.busy_s
+        device["window_s"] = r.trace.window_s
+        result["breakdown"] = {"device_ops": r.trace.top_ops(),
+                               "idle_gaps": r.trace.top_idle()}
     result["checks"] = checks
-    return result, lowerings.n, numbers
+    return result
+
+
+def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
+        t_start: float) -> tuple:
+    """(result line, programs lowered inside the window, every number the
+    kind computed against the reference) of one run (:func:`measure`,
+    :func:`line`)."""
+    r = measure(cell, seed, seconds, traced, t_start)
+    return line(r), r.lowered, r.numbers

@@ -176,6 +176,22 @@ class TrainCell:
             "failed": self.total - step}
         return self.counters
 
+    # -- work at the configuration's real widths (bench/work.py) -------------
+
+    def ops_per_sample(self) -> int:
+        """Forward and backward operations of one training sample."""
+        return work.train_ops_per_row(self.sizes)
+
+    def kernel_work(self, kernel: str, rows: int, launches: int):
+        """(operations, HBM bytes) of ``launches`` launches of ``kernel``
+        over ``rows`` rows in all, or None for a kernel this kind does not
+        count."""
+        if kernel != "fused_train":
+            return None
+        return (rows * work.train_ops_per_row(self.sizes),
+                work.train_kernel_bytes(self.sizes, rows, launches,
+                                        self.tr["optimizer"]))
+
     # -- correctness -----------------------------------------------------------
 
     def readings(self, precision: str = "highest",

@@ -1,8 +1,9 @@
-"""The program's own spans, op scopes and counters in a profiler trace of
-the window, and a traced run that reads them.
+"""The program's own spans and op scopes in a profiler trace of the
+window, the readings taken from them, and a probe that prints them all.
 
 ``bench/trace.py`` reduces a trace by HLO op name and by the harness's own
-spans.  This module reads what the program records itself:
+spans, and through :func:`load` and :func:`reduce_program` by what the
+program records itself, on every traced run:
 
 * op scopes: every device op's ``tf_op`` stat, its name-scope path
   (``jit(chunk_step)/simulate/jit(sample_batch)/...``), kept in the event
@@ -14,17 +15,20 @@ spans.  This module reads what the program records itself:
   line.  Each device-idle instant of the window is put down to the
   innermost program span open at that instant on the window's own thread
   (``unspanned`` where none is); spans on other threads (the checkpoint
-  flush's worker) are never blamed;
-* counters: ``repro.obs.COUNTS`` before and after the window.
+  flush's worker) are never blamed.
+
+The counters (``repro.obs.COUNTS`` over the window) are the harness's, on
+every run.  :func:`readings` holds the formulas of the metric readers in
+``bench/metrics/`` that read spans, scopes and counters.
 
     PYTHONPATH=src python3 -m bench.program_trace --workload <cell> \\
         --seed <n> [--seconds <s>] [--keep <file.xplane.pb>]
 
 runs one ``--trace 1`` run of the cell through ``bench.harness`` and prints
-its result line with ``program``: the readings below (``metrics``), the
-traced window's samples per second, the idle split by program span, each span's loop-thread and self time, the
+its result line with ``program``: the traced window's samples per second,
+the idle split by program span, each span's loop-thread and self time, the
 scope seconds, the lag from each chunk's dispatch to its program's start
-on the device, the counter deltas, and the cost of one span with no trace
+on the device, the counters, and the cost of one span with no trace
 running.
 """
 
@@ -154,11 +158,12 @@ def load(path, spans) -> list:
         lines = {}
         if plane.name.startswith("/device:"):
             ops = tf_ops.get(plane.name, {})
+            paths = {name: scope_path(tf_op) for name, tf_op in ops.items()}
             for line in plane.lines:
                 if line.name in ("XLA Ops", "XLA Modules"):
                     lines[line.name] = [
                         (e.name, e.start_ns, e.duration_ns,
-                         scope_path(ops[e.name]) if e.name in ops else ())
+                         paths.get(e.name, ()))
                         for e in line.events]
         else:
             # threads may share a name: a line is its place and its name
@@ -178,6 +183,7 @@ class ProgramReduced:
     n_devices: int
     scope_seconds: dict             # scope -> device seconds (all devices)
     idle_by_program_span: dict      # innermost loop-thread span -> idle s
+    idle_by_cause: dict             # the same, unspanned by harness span
     span_seconds: dict              # loop-thread span -> seconds in window
     self_seconds: dict              # loop-thread span -> seconds innermost
     dispatch_lags_s: list | None    # chunk program start - its dispatch
@@ -212,10 +218,9 @@ def innermost(spans, t0, t1) -> list:
     return out
 
 
-def _overlaps(segments, gaps, weight=1.0) -> collections.Counter:
-    """Seconds of each segment name inside the gaps (both sorted, each
-    disjoint), times ``weight``."""
-    out = collections.Counter()
+def _pieces(segments, gaps):
+    """``(name, start, end)`` of each part of ``segments`` that lies inside
+    ``gaps`` (both sorted, each disjoint)."""
     j = 0
     for g0, g1 in gaps:
         while j < len(segments) and segments[j][1] <= g0:
@@ -223,20 +228,20 @@ def _overlaps(segments, gaps, weight=1.0) -> collections.Counter:
         k = j
         while k < len(segments) and segments[k][0] < g1:
             s, e, name = segments[k]
-            ov = min(e, g1) - max(s, g0)
-            if ov > 0:
-                out[name] += ov * 1e-9 * weight
+            if min(e, g1) > max(s, g0):
+                yield name, max(s, g0), min(e, g1)
             k += 1
-    return out
 
 
 def reduce_program(planes, spans, window: str = WINDOW) -> ProgramReduced:
     """Reduce ``planes`` (as :func:`load` gives them) to a
     :class:`ProgramReduced`: op scopes, and the idle gaps of the window
     split by the program spans (those named in ``spans``) of the window's
-    own thread."""
+    own thread; by cause, the unspanned part of each gap is put down to
+    the harness span that overlaps it most (``trace.HARNESS_SPANS``, or
+    ``host``), as ``trace.reduce_planes`` puts down a whole gap."""
     spans = frozenset(spans)
-    win, devices, by_line = None, [], {}
+    win, devices, by_line, harness = None, [], {}, []
     for pname, lines in planes:
         if pname.startswith("/device:TPU:"):
             devices.append(lines)
@@ -248,6 +253,8 @@ def reduce_program(planes, spans, window: str = WINDOW) -> ProgramReduced:
                 elif name in spans:
                     by_line.setdefault((pname, lname), []).append(
                         (start, start + dur, name))
+                elif name in trace.HARNESS_SPANS:
+                    harness.append((start, start + dur, name))
     if win is None:
         raise ValueError(f"trace has no host span named {window!r}")
     if not devices:
@@ -255,10 +262,13 @@ def reduce_program(planes, spans, window: str = WINDOW) -> ProgramReduced:
     w0, w1, loop_line = win
     loop = sorted(by_line.get(loop_line, ()))
     segments = innermost(loop, w0, w1)
+    harness.sort()
+    starts = [s for s, _e, _n in harness]
     n = len(devices)
     scope_ns = collections.Counter()
     busy_ns = 0.0
-    idle = collections.Counter()
+    idle, cause = collections.Counter(), collections.Counter()
+    weight = 1.0 / n
     modules = []
     for lines in devices:
         intervals = []
@@ -274,7 +284,14 @@ def reduce_program(planes, spans, window: str = WINDOW) -> ProgramReduced:
         edges = [w0] + [x for iv in merged for x in iv] + [w1]
         gaps = [(g0, g1) for g0, g1 in zip(edges[::2], edges[1::2])
                 if g1 > g0]
-        idle.update(_overlaps(segments, gaps, 1.0 / n))
+        dev_idle, dev_cause = collections.Counter(), collections.Counter()
+        for name, a, b in _pieces(segments, gaps):
+            dev_idle[name] += (b - a) * 1e-9 * weight
+            if name == UNSPANNED:
+                name = trace._blame(starts, harness, a, b)
+            dev_cause[name] += (b - a) * 1e-9 * weight
+        idle.update(dev_idle)
+        cause.update(dev_cause)
         modules += [start for name, start, _d, _s
                     in lines.get("XLA Modules", ())
                     if name.startswith(CHUNK_PROGRAM) and w0 <= start < w1]
@@ -292,21 +309,22 @@ def reduce_program(planes, spans, window: str = WINDOW) -> ProgramReduced:
     return ProgramReduced(
         window_s=(w1 - w0) * 1e-9, busy_s=busy_ns * 1e-9 / n, n_devices=n,
         scope_seconds={k: v * 1e-9 for k, v in scope_ns.items()},
-        idle_by_program_span=dict(idle),
+        idle_by_program_span=dict(idle), idle_by_cause=dict(cause),
         span_seconds={k: v * 1e-9 for k, v in span_ns.items()},
         self_seconds={k: v * 1e-9 for k, v in self_ns.items()
                       if k != UNSPANNED},
         dispatch_lags_s=lags)
 
 
-def readings(p: ProgramReduced, counts: dict, steps: int) -> dict:
-    """The per-step and share readings of one traced window: device time
-    under ``simulate`` and ``stage``, ``runner.dispatch``'s self time, the
-    loop thread's time in checkpoint restore, wait and snapshot, the share
-    of device-idle time under no program span, and host fetches, each per
-    step of the window (``steps``); ``counts`` are the counter deltas.  A
-    reading whose scope, span or counter the trace lacks (a program
-    without them) is left out."""
+def readings(p, counts: dict, steps: int) -> dict:
+    """The per-step and share readings of one traced window (``p``, a
+    :class:`ProgramReduced` or a ``trace.Reduced``): device time under
+    ``simulate`` and ``stage``, ``runner.dispatch``'s self time, the loop
+    thread's time in ``runner.fetch`` and in checkpoint restore, wait and
+    snapshot, the share of device-idle time under no program span, and
+    host fetches, each per step of the window (``steps``); ``counts`` are
+    the counter deltas.  A reading whose scope, span or counter the trace
+    lacks (a program without them) is left out."""
     if not steps:
         return {}
     per_step = 1e6 / steps
@@ -318,6 +336,9 @@ def readings(p: ProgramReduced, counts: dict, steps: int) -> dict:
     if "runner.dispatch" in p.self_seconds:
         out["train_dispatch_us_per_step"] = (
             p.self_seconds["runner.dispatch"] * per_step)
+    if "runner.fetch" in p.span_seconds:
+        out["train_fetch_us_per_step"] = (
+            p.span_seconds["runner.fetch"] * per_step)
     ckpt = [p.span_seconds[k] for k in ("ckpt.snapshot", "ckpt.wait",
                                         "ckpt.restore")
             if k in p.span_seconds]
@@ -330,6 +351,16 @@ def readings(p: ProgramReduced, counts: dict, steps: int) -> dict:
     if "d2h" in counts:
         out["train_d2h_per_step"] = counts["d2h"] / steps
     return out
+
+
+def read(run, name: str):
+    """Reading ``name`` of :func:`readings` for a harness ``Run``: its trace
+    and counters, per step of its window; None untraced, or where the run
+    has nothing to read it from."""
+    if run.trace is None:
+        return None
+    return readings(run.trace, run.counters,
+                    run.counters.get("steps", 0)).get(name)
 
 
 def _top(d: dict) -> list:
@@ -369,53 +400,26 @@ def span_cost_ns(reps: int = 200_000) -> dict:
 
 def traced_run(cell, seed: int, seconds: float, t_start: float,
                keep=None) -> dict:
-    """One ``--trace 1`` run of ``cell`` through ``bench.harness``, its
-    trace also read for the program's spans and scopes and its window's
-    counter deltas taken; the trace is copied to ``keep`` if given."""
-    import shutil
-    from unittest import mock
-
+    """One ``--trace 1`` run of ``cell`` through ``bench.harness``: its
+    result line with ``program``, what the harness's ``Run`` holds of the
+    program's spans, scopes and counters; the trace is copied to ``keep``
+    if given."""
     from bench import harness
-    from repro import obs
 
-    got = {}
-    base = harness.kind(cell.traffic["kind"])
-
-    class Counted(base):
-        def window(self):
-            before = collections.Counter(obs.COUNTS)
-            counters = super().window()
-            got["steps"] = counters["steps"]
-            got["samples_per_s"] = counters["samples"] / counters["window_s"]
-            got["counts"] = {k: v - before[k] for k, v in obs.COUNTS.items()
-                             if v != before[k]}
-            return counters
-
-    reduce_xplane = trace.reduce_xplane
-
-    def reduce_both(path, window=WINDOW):
-        got["program"] = reduce_program(load(path, obs.SPANS), obs.SPANS,
-                                        window)
-        if keep:
-            shutil.copy(path, keep)
-        return reduce_xplane(path, window)
-
-    with mock.patch.object(harness, "kind", lambda _name: Counted), \
-            mock.patch.object(trace, "reduce_xplane", reduce_both):
-        result, lowered, _ = harness.run(cell, seed, seconds, True, t_start)
-    p, counts = got["program"], got["counts"]
-    steps = got["steps"]
-    lags = p.dispatch_lags_s
+    run = harness.measure(cell, seed, seconds, True, t_start,
+                          keep_trace=keep)
+    result = harness.line(run)
+    t, c = run.trace, run.counters
+    lags = t.dispatch_lags_s
     result["program"] = {
-        "metrics": readings(p, counts, steps),
-        "steps": steps,
-        "samples_per_s": got["samples_per_s"],
-        "idle_by_program_span": _top(p.idle_by_program_span),
-        "span_seconds": _top(p.span_seconds),
-        "self_seconds": _top(p.self_seconds),
-        "scope_seconds": _top(p.scope_seconds),
-        "counts": counts,
-        "lowered_in_window": lowered,
+        "steps": c["steps"],
+        "samples_per_s": c["samples"] / c["window_s"],
+        "idle_by_program_span": _top(t.idle_by_program_span),
+        "span_seconds": _top(t.span_seconds),
+        "self_seconds": _top(t.self_seconds),
+        "scope_seconds": _top(t.scope_seconds),
+        "counts": c,
+        "lowered_in_window": run.lowered,
         "dispatch_lag_s": None if lags is None else {
             "n": len(lags), "min": min(lags, default=None),
             "median": statistics.median(lags) if lags else None,

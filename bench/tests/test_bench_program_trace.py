@@ -1,20 +1,25 @@
 """The reduction of the program's own spans and op scopes
-(``bench/program_trace.py``): on hand-made planes, and on the small trace
-recorded on a TPU v5e (``test_bench_trace.py``), whose every existing
-reading is pinned here so that a change to the reduction shows."""
+(``bench/program_trace.py``) and the metric readers that read them: on
+hand-made planes, and on the small trace recorded on a TPU v5e
+(``test_bench_trace.py``), whose every reading from before the program's
+spans were read is pinned here so that a change to the reduction shows."""
 
-import dataclasses
 import json
 import pathlib
 
 import pytest
 
-from bench import harness, peaks, program_trace as pt, spec, trace, work
+from bench import harness, peaks, program_trace as pt, spec, trace
 from repro import obs
 
 DATA = pathlib.Path(__file__).resolve().parent / "data"
 FIXTURE = DATA / "v5e_train_serve.xplane.pb"
 PIN = DATA / "v5e_train_serve.pin.json"
+# the readers of the program's spans, scopes and counters
+READERS = ("train_sim_us_per_step", "train_stage_us_per_step",
+           "train_dispatch_us_per_step", "train_fetch_us_per_step",
+           "train_ckpt_us_per_step", "train_idle_unspanned_pct",
+           "train_d2h_per_step")
 
 
 def planes():
@@ -68,6 +73,10 @@ def test_reduce_hand_made_planes():
         "chunk_step": 130e-9, "simulate": 40e-9, "sample_batch": 40e-9,
         "stage": 40e-9, "fused_train_call": 50e-9})
     assert r.dispatch_lags_s == pytest.approx([55e-9])
+    # by cause, the unspanned part goes to the harness span around it
+    assert r.idle_by_cause == pytest.approx({
+        **{k: v for k, v in r.idle_by_program_span.items()
+           if k != pt.UNSPANNED}, "train": 360e-9})
 
 
 def test_readings_per_step():
@@ -77,6 +86,7 @@ def test_readings_per_step():
         "train_sim_us_per_step": 40e-9 * 1e5,
         "train_stage_us_per_step": 40e-9 * 1e5,
         "train_dispatch_us_per_step": 50e-9 * 1e5,
+        "train_fetch_us_per_step": 100e-9 * 1e5,
         "train_ckpt_us_per_step": 200e-9 * 1e5,
         "train_idle_unspanned_pct": 100.0 * 360 / 870,
         "train_d2h_per_step": 2.5})
@@ -117,27 +127,64 @@ def test_recorded_v5e_trace_scopes():
     # the trace predates the program's spans: all idle time is unspanned
     assert r.idle_by_program_span == pytest.approx(
         {pt.UNSPANNED: r.window_s - r.busy_s})
-    # both readers see the same device events
-    old = dict(trace.load_planes(FIXTURE))["/device:TPU:0"]
-    new = dict(loaded)["/device:TPU:0"]
-    for line in ("XLA Ops", "XLA Modules"):
-        assert [e[:3] for e in new[line]] == old[line]
+    # the harness's reduction carries the program's
+    assert (base.scope_seconds, base.idle_by_program_span) == (
+        pt.reduce_program(pt.load(FIXTURE, ()), ()).scope_seconds,
+        r.idle_by_program_span)
+
+
+def _run(reduced, counters, setup_s=1.0):
+    cell = spec.load("fpga-train-sgd")
+    return harness.Run(cell=cell, kind=harness.kind("train")(cell, 1),
+                       peaks=peaks.PEAKS["TPU v5 lite"], setup_s=setup_s,
+                       counters=counters, trace=reduced)
 
 
 def test_existing_readings_pinned_on_recorded_trace():
     """Every field of ``trace.Reduced`` and every metric reader of the
-    benchmark reads on the recorded trace what it read when the program's
-    spans were added."""
+    benchmark that predates the reading of the program's spans reads on
+    the recorded trace what it read when the program's spans were added;
+    the readers added since find nothing to read in a trace that has no
+    program spans and a window without counters."""
     pin = json.loads(PIN.read_text())
-    r = trace.reduce_xplane(FIXTURE)
-    assert dataclasses.asdict(r) == pin["reduced"]
+    r = trace.reduce_xplane(FIXTURE, spans=obs.SPANS)
+    assert {k: getattr(r, k) for k in pin["reduced"]} == pin["reduced"]
     assert r.top_ops() == pin["top_ops"]
     assert r.top_idle() == pin["top_idle"]
-    cell = spec.load("fpga-train-sgd")
-    run = harness.Run(cell=cell, sizes=work.layer_sizes(cell.config),
-                      peaks=peaks.PEAKS["TPU v5 lite"],
-                      setup_s=pin["setup_s"], counters=pin["counters"],
-                      trace=r)
+    run = _run(r, pin["counters"], pin["setup_s"])
+    cell = run.cell
     got = {m["name"]: harness.reader(m["name"])(run)
            for m in cell.per_layer + cell.end_to_end}
-    assert got == pin["metrics"]
+    assert {k: got.pop(k) for k in pin["metrics"]} == pin["metrics"]
+    assert set(got) == set(READERS)
+    assert all(v is None for v in got.values()), got
+
+
+@pytest.mark.parametrize("source", ["recorded", "hand_made"])
+@pytest.mark.parametrize("name", READERS)
+def test_program_readers_equal_readings(name, source):
+    """Each reader of ``bench/metrics`` gives what ``program_trace.readings``
+    gives for the same trace and counters, per step of the window."""
+    counters = {"steps": 10, "samples": 2560, "window_s": 1e-6,
+                "attempted": 10, "failed": 0, "d2h": 25, "runner.chunks": 1}
+    if source == "recorded":
+        r = trace.reduce_xplane(FIXTURE, spans=obs.SPANS)
+    else:
+        r = trace.reduce_program_planes(planes(), obs.SPANS)
+    want = pt.readings(r, counters, counters["steps"]).get(name)
+    assert harness.reader(name)(_run(r, counters)) == want
+    if source == "hand_made":
+        assert want is not None
+    assert harness.reader(name)(_run(None, counters)) is None
+
+
+def test_idle_gaps_name_program_spans():
+    """``breakdown.idle_gaps`` puts idle time down to the innermost program
+    span, and to the harness's span only where none is open."""
+    r = trace.reduce_program_planes(planes(), obs.SPANS)
+    names = [k for k, _v in r.top_idle()]
+    assert names[0] == "train" and "runner.fetch" in names
+    assert set(names) == {"train", "runner.retire", "runner.fetch",
+                          "ckpt.snapshot", "ckpt.wait", "runner.dispatch"}
+    assert sum(v for _k, v in r.top_idle()) == pytest.approx(
+        r.window_s - r.busy_s)

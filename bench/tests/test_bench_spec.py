@@ -52,7 +52,10 @@ def test_names_units_and_keys():
                                "program_counter", "host_clock")
     for w in BENCH["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert w["chips"] == 1 and len(w["why"]) <= 200
+        assert w["chips"] in (1, 4) and len(w["why"]) <= 200
+    # at most half the cells, rounded down, on four chips; one always may
+    four = sum(w["chips"] == 4 for w in BENCH["workloads"])
+    assert four <= max(1, len(CELLS) // 2)
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -76,6 +79,10 @@ def test_config_files(cfg):
     body = json.loads((ROOT / cfg["file"]).read_text())
     assert cfg["file"].startswith("bench/")
     assert body["name"] == cfg["name"] and body["source"] == cfg["source"]
-    assert body["reduced"] == cfg["reduced"] == []
-    assert {"train", "calibration", "serve"} <= set(body["precision"])
-    assert any(w["config"] == cfg["name"] for w in BENCH["workloads"])
+    assert body["reduced"] == cfg["reduced"]
+    assert len(cfg["reduced"]) <= 16 and all(NAME.match(k)
+                                             for k in cfg["reduced"])
+    # the precision of every path its cells run (the traffic's kind)
+    kinds = {spec.load(w["name"]).traffic["kind"]
+             for w in BENCH["workloads"] if w["config"] == cfg["name"]}
+    assert kinds and kinds <= set(body["precision"])

@@ -12,7 +12,13 @@ its measured loop.  Within it:
 * program launches: events on the ``XLA Modules`` line;
 * idle gaps: the stretches of the window in which no device operation ran,
   each put down to the harness span (``train``, ``enqueue``, ``poll``,
-  ``drain``) that overlaps it most, or to ``host`` where none does.
+  ``drain``) that overlaps it most, or to ``host`` where none does
+  (``idle_by_span``);
+* the program's own record (``bench/program_trace.py``), read from a trace
+  file by :func:`reduce_xplane`: device time under each name scope, the
+  window thread's program spans, and the idle time put down to the
+  innermost program span open, the harness span where none is
+  (``idle_by_cause``, which ``breakdown.idle_gaps`` reports).
 
 Device and host events share the profiler's clock; they can disagree by
 some hundreds of microseconds, which moves a gap's attribution, not its
@@ -48,6 +54,10 @@ def _union(intervals):
     return out
 
 
+def _top(d: dict, n: int) -> list:
+    return sorted(([k, v] for k, v in d.items()), key=lambda kv: -kv[1])[:n]
+
+
 @dataclasses.dataclass
 class Reduced:
     window_s: float
@@ -57,6 +67,17 @@ class Reduced:
     op_count: dict                  # op name -> events
     launches: int                   # XLA module executions (all devices)
     idle_by_span: dict              # harness span -> idle seconds
+    # the program's own record, from a trace file (empty from bare planes):
+    # name scope -> device seconds (all devices)
+    scope_seconds: dict = dataclasses.field(default_factory=dict)
+    # window-thread program span -> seconds in the window, and innermost
+    span_seconds: dict = dataclasses.field(default_factory=dict)
+    self_seconds: dict = dataclasses.field(default_factory=dict)
+    # innermost window-thread program span (or ``unspanned``) -> idle s
+    idle_by_program_span: dict = dataclasses.field(default_factory=dict)
+    # the same, with the unspanned part put down to the harness spans
+    idle_by_cause: dict = dataclasses.field(default_factory=dict)
+    dispatch_lags_s: list | None = None   # chunk program start - dispatch
 
     def kernel_seconds(self, prefix: str) -> float:
         return sum(v for k, v in self.op_seconds.items()
@@ -67,12 +88,10 @@ class Reduced:
                    if k.startswith(prefix))
 
     def top_ops(self, n: int = 10) -> list:
-        return sorted(([k, v] for k, v in self.op_seconds.items()),
-                      key=lambda kv: -kv[1])[:n]
+        return _top(self.op_seconds, n)
 
     def top_idle(self, n: int = 10) -> list:
-        return sorted(([k, v] for k, v in self.idle_by_span.items()),
-                      key=lambda kv: -kv[1])[:n]
+        return _top(self.idle_by_cause or self.idle_by_span, n)
 
 
 def reduce_planes(planes, window: str = "window",
@@ -146,30 +165,33 @@ def _blame(starts, host_spans, g0, g1) -> str:
     return name
 
 
-def load_planes(path, host_names=("window",) + HARNESS_SPANS) -> list:
-    """Read an ``.xplane.pb`` into the plain form :func:`reduce_planes`
-    takes: the device planes' op and module lines, and the host events
-    named in ``host_names``."""
-    from jax.profiler import ProfileData
+def reduce_program_planes(planes, spans=(), window: str = "window"
+                          ) -> Reduced:
+    """Reduce planes as ``program_trace.load`` gives them: by HLO op name
+    and harness span as :func:`reduce_planes` does, and by the program's
+    name scopes and its spans named in ``spans``."""
+    from bench import program_trace
 
-    host_names = frozenset(host_names)
-    out = []
-    for plane in ProfileData.from_file(str(path)).planes:
-        device = plane.name.startswith("/device:")
-        lines = {}
-        for line in plane.lines:
-            if device:
-                if line.name in ("XLA Ops", "XLA Modules"):
-                    lines[line.name] = [(e.name, e.start_ns, e.duration_ns)
-                                        for e in line.events]
-            else:
-                kept = [(e.name, e.start_ns, e.duration_ns)
-                        for e in line.events if e.name in host_names]
-                if kept:
-                    lines[line.name] = kept
-        out.append((plane.name, lines))
-    return out
+    harness = frozenset((window,) + HARNESS_SPANS)
+    bare = [(pname, {ln: [e[:3] for e in events
+                          if pname.startswith("/device:") or e[0] in harness]
+                     for ln, events in lines.items()})
+            for pname, lines in planes]
+    p = program_trace.reduce_program(planes, spans, window)
+    return dataclasses.replace(
+        reduce_planes(bare, window=window),
+        scope_seconds=p.scope_seconds, span_seconds=p.span_seconds,
+        self_seconds=p.self_seconds,
+        idle_by_program_span=p.idle_by_program_span,
+        idle_by_cause=p.idle_by_cause, dispatch_lags_s=p.dispatch_lags_s)
 
 
-def reduce_xplane(path, window: str = "window") -> Reduced:
-    return reduce_planes(load_planes(path), window=window)
+def reduce_xplane(path, window: str = "window", spans=()) -> Reduced:
+    """Reduce an ``.xplane.pb`` by :func:`reduce_program_planes`; ``spans``
+    are the program's span names (``repro.obs.SPANS``), given by the caller
+    so that a span the program adds is read with no edit here."""
+    from bench import program_trace
+
+    spans = tuple(spans)
+    planes = program_trace.load(path, spans + HARNESS_SPANS + (window,))
+    return reduce_program_planes(planes, spans, window)
